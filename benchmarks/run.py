@@ -12,8 +12,8 @@ Sections:
   vos   — system-wide Value-of-Service per policy (paper §3/§4.2.3)
   exec  — real execution of the scheduled 16-task workload (host vs device)
   serve — request-scheduling policies on the serving engine
-  kern  — kernel micro-benches (CPU interpret mode: correctness-path
-          timings; TPU wall-times come from real hardware)
+  kern  — kernel micro-benches at toy shapes; each row's unit names the
+          platform it ran on (us_cpu_interpret on the CPU, us_tpu compiled)
   roofline — summary of the dry-run roofline table (if results exist)
 
 Output: CSV-ish `section,name,value,unit` lines + human tables.
@@ -200,7 +200,10 @@ def bench_kernels() -> None:
     from repro.kernels.decode_attention import decode_attention
     from repro.kernels.kmeans import kmeans_assign
     from repro.kernels.window_agg import window_agg
+    from repro.kernels import platform
     rng = np.random.default_rng(0)
+    unit = "us_" + jax.default_backend() + (
+        "_interpret" if platform.interpret() else "")
 
     def timeit(fn, *args, n=3, **kw):
         fn(*args, **kw)  # compile/warm
@@ -212,21 +215,21 @@ def bench_kernels() -> None:
     q = jnp.asarray(rng.normal(0, 1, (1, 256, 4, 64)), jnp.float32)
     k = jnp.asarray(rng.normal(0, 1, (1, 256, 2, 64)), jnp.float32)
     us = timeit(flash_attention, q, k, k, block_q=64, block_k=64)
-    row("kern", "flash_attention_256x4x64", f"{us:.0f}", "us_interp")
+    row("kern", "flash_attention_256x4x64", f"{us:.0f}", unit)
 
     qd = jnp.asarray(rng.normal(0, 1, (4, 8, 64)), jnp.float32)
     kd = jnp.asarray(rng.normal(0, 1, (4, 512, 2, 64)), jnp.float32)
     us = timeit(decode_attention, qd, kd, kd)
-    row("kern", "decode_attention_c512", f"{us:.0f}", "us_interp")
+    row("kern", "decode_attention_c512", f"{us:.0f}", unit)
 
     x = jnp.asarray(rng.normal(0, 1, (2048, 16)), jnp.float32)
     c = jnp.asarray(rng.normal(0, 1, (16, 16)), jnp.float32)
     us = timeit(kmeans_assign, x, c)
-    row("kern", "kmeans_assign_2048x16x16", f"{us:.0f}", "us_interp")
+    row("kern", "kmeans_assign_2048x16x16", f"{us:.0f}", unit)
 
     w = jnp.asarray(rng.normal(0, 1, (1024, 8)), jnp.float32)
     us = timeit(window_agg, w, window=16, agg="mean")
-    row("kern", "window_agg_1024x8_w16", f"{us:.0f}", "us_interp")
+    row("kern", "window_agg_1024x8_w16", f"{us:.0f}", unit)
 
 
 def bench_roofline() -> None:

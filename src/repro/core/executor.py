@@ -20,6 +20,7 @@ import dataclasses
 import time
 from typing import Any, Callable, Dict, List, Mapping, Optional, Tuple
 
+import jax
 
 from repro.core.cost_model import LearnedCostModel
 from repro.core.dag import PipelineDAG, Task
@@ -75,9 +76,9 @@ class Executor:
     """Executes a scheduled DAG with real backends.
 
     ``backend_of(pe)`` maps a PE to a backend key; the default sends
-    frontend PEs to "host" and everything else to "device". Tasks lacking
-    the chosen backend fall back to any available one (flexibility is the
-    point of the flexible binary — semantics are identical).
+    frontend PEs to "host" and everything else to "device". A task that
+    lacks the backend its PE asks for is an error: running it elsewhere
+    would hide that the placement was not executed as planned.
     """
 
     def __init__(self, pool: ResourcePool,
@@ -91,12 +92,11 @@ class Executor:
 
     def _resolve(self, task: Task, pe: str) -> Tuple[str, Callable]:
         want = self._backend_of(pe)
-        if want in task.backends:
-            return want, task.backends[want]
-        if task.backends:
-            k = sorted(task.backends)[0]
-            return k, task.backends[k]
-        raise ValueError(f"task {task.name!r} has no executable backends")
+        if want not in task.backends:
+            raise ValueError(
+                f"task {task.name!r} placed on {pe!r} needs backend "
+                f"{want!r}; it has {sorted(task.backends)}")
+        return want, task.backends[want]
 
     def execute(self, dag: PipelineDAG, schedule: Schedule,
                 inputs: Optional[Mapping[str, Any]] = None, *,
@@ -189,8 +189,7 @@ class Executor:
                 args = [inputs[task.name]] + args
             kind, fn = self._resolve(task, a.pe)
             t0 = time.perf_counter()
-            out = fn(*args, **task.params)
-            out = _block(out)
+            out = jax.block_until_ready(fn(*args, **task.params))
             dt = (time.perf_counter() - t0) * slow.get(a.pe, 1.0)
             outputs[task.name] = out
             copies[task.name] = {a.pe}
@@ -208,12 +207,3 @@ class Executor:
             sanitize.check_execution_report(report, dag)
         return report
 
-
-def _block(x: Any) -> Any:
-    """Block-until-ready for jax outputs (accurate timing), pass-through
-    otherwise; handles tuples/dicts of arrays."""
-    try:
-        import jax
-        return jax.block_until_ready(x)
-    except Exception:
-        return x

@@ -27,7 +27,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.compat import axis_size
 
 _QBLOCK = 256
 
@@ -39,7 +38,7 @@ def hierarchical_psum(x: jax.Array, *, inner_axis: str = "data",
     Mathematically identical to psum over both axes; on hardware the outer
     (DCN) axis carries only the scattered shard.
     """
-    n_inner = axis_size(inner_axis)
+    n_inner = jax.lax.axis_size(inner_axis)
     flat = x.reshape(-1)
     pad = (-flat.shape[0]) % n_inner
     if pad:
@@ -82,7 +81,7 @@ def int8_allreduce(x: jax.Array, *, axis: str = "data",
     quantization residual (same shape as x, f32), added before quantizing.
     Wire bytes per chip ≈ 2 × size × 1 B (vs 8 B for f32 ring) + scales.
     """
-    n_dev = axis_size(axis)
+    n_dev = jax.lax.axis_size(axis)
     flat = x.astype(jnp.float32).reshape(-1)
     if error is not None:
         flat = flat + error.reshape(-1)

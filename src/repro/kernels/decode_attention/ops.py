@@ -13,14 +13,16 @@ from repro.kernels.decode_attention.decode_attention import (
     decode_attention_kernel)
 
 
-@functools.partial(jax.jit, static_argnames=("softcap", "scale", "block_c",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("softcap", "scale", "block_c"))
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      valid: Optional[jax.Array] = None, *,
                      softcap: float = 0.0, scale: Optional[float] = None,
-                     block_c: int = 128, interpret: bool = True) -> jax.Array:
+                     block_c: int = 512) -> jax.Array:
     """q: (B, Hq, D) · k,v: (B, C, Hkv, D) · valid: (B, C) bool →
-    (B, Hq, D). Never expands KV to query heads (bandwidth-optimal)."""
+    (B, Hq, D). Never expands KV to query heads (bandwidth-optimal).
+
+    The cache is read in place when C is a multiple of the block and D
+    of 128 lanes; otherwise it is padded (a copy) to get there."""
     B, Hq, D = q.shape
     C, Hkv = k.shape[1], k.shape[2]
     G = Hq // Hkv
@@ -42,6 +44,5 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         v = jnp.pad(v, [(0, 0), (0, 0), (0, 0), (0, pad_d)])
 
     out = decode_attention_kernel(qg, k, v, valid.astype(jnp.int32),
-                                  softcap=softcap, scale=scale,
-                                  block_c=bc, interpret=interpret)
+                                  softcap=softcap, scale=scale, block_c=bc)
     return out[..., :D].reshape(B, Hq, D)

@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import platform
+
 NEG_INF = -1e30
 
 
@@ -94,8 +96,8 @@ def flash_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool = True, window: int = 0,
                            softcap: float = 0.0, scale=None,
                            seq_len=None,
-                           block_q: int = 128, block_k: int = 128,
-                           interpret: bool = True) -> jax.Array:
+                           block_q: int = 128, block_k: int = 128
+                           ) -> jax.Array:
     """q,k,v: (BH, S_pad, D_pad), S_pad % block == 0. ``seq_len`` is the
     true (pre-padding) length — padded keys are masked out; padded q rows
     produce garbage the ops.py wrapper slices off."""
@@ -124,5 +126,5 @@ def flash_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q,), jnp.float32),      # running denom
             pltpu.VMEM((block_q, D), jnp.float32),    # output accumulator
         ],
-        interpret=interpret,
+        interpret=platform.interpret(),
     )(q, k, v)

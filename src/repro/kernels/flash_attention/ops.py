@@ -7,7 +7,8 @@ Handles what the kernel leaves to the caller:
     decode_attention kernel's job where bandwidth actually dominates);
   * padding S to the block size and D to the 128-lane multiple, with true
     ``seq_len`` masking inside the kernel;
-  * ``interpret=True`` on CPU (this container), compiled on real TPUs.
+  * where it runs: compiled on a TPU, interpreted on the CPU
+    (:func:`repro.kernels.platform.interpret`).
 """
 
 from __future__ import annotations
@@ -33,13 +34,11 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "window", "softcap", "scale", "block_q", "block_k",
-    "interpret"))
+    "causal", "window", "softcap", "scale", "block_q", "block_k"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int = 0,
                     softcap: float = 0.0, scale: Optional[float] = None,
-                    block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    block_q: int = 128, block_k: int = 128) -> jax.Array:
     """q: (B, S, Hq, D) · k,v: (B, S, Hkv, D) → (B, S, Hq, D)."""
     B, S, Hq, D = q.shape
     Hkv = k.shape[2]
@@ -65,6 +64,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     out = flash_attention_kernel(
         qk, kk, vk, causal=causal, window=window, softcap=softcap,
         scale=scale, seq_len=S, block_q=min(bq, qk.shape[1]),
-        block_k=min(bk, kk.shape[1]), interpret=interpret)
+        block_k=min(bk, kk.shape[1]))
     out = out[:, :S, :D].reshape(B, Hq, S, D).transpose(0, 2, 1, 3)
     return out

@@ -1,17 +1,20 @@
-"""k-means assignment — Pallas TPU kernel (MXU formulation).
+"""k-means assignment — Pallas TPU kernel (lane-dense VPU formulation).
 
 The hot loop of the paper's k-means / sweep-clustering / train-cluster DS
 operators (the dominant ``ml``-family tasks of the Fig. 5 workload). The
-Euclidean distance matrix is rewritten as a matmul so the MXU does the
-heavy lifting:
+points are few-featured (3 columns after the pipeline's feature filter),
+so an MXU matmul over a 128-padded feature dim would be almost all
+padding. Instead the wrapper hands the kernel the points transposed,
+``(D, N)``: points on the 128 lanes, features on the sublanes. Per grid
+step a ``(D, block_n)`` slab is resident in VMEM and, for each centroid,
 
-    ‖x − c‖² = ‖x‖² − 2·x·cᵀ + ‖c‖²
+    d2_k = Σ_d (x_d − c_kd)²
 
-Per grid step a (block_n, D) slab of points is resident in VMEM, the full
-(K, D) centroid matrix rides along (clusters are small: K ≤ ~1024), and the
-(block_n, K) score tile comes off the MXU; argmin + min reduce on the VPU.
-Single-pass, no cross-step state — the simplest possible Pallas shape, and
-~10× the arithmetic intensity of the naive subtract-square-sum form.
+is a sublane reduction to one lane-dense ``(1, block_n)`` row — the same
+subtract-square-sum formula as the oracle, so distances agree to
+rounding. A running strict ``<`` keeps the first minimum (``argmin``
+semantics). Outputs are ``(1, N)`` rows: lane-dense, so Mosaic's layout
+matches XLA's at any N.
 """
 
 from __future__ import annotations
@@ -22,42 +25,46 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _kernel(x_ref, c_ref, a_ref, d_ref, *, k_real: int):
-    x = x_ref[...].astype(jnp.float32)                  # (bn, D)
-    c = c_ref[...].astype(jnp.float32)                  # (K, D)
-    xx = (x * x).sum(axis=1, keepdims=True)             # (bn, 1)
-    cc = (c * c).sum(axis=1)                            # (K,)
-    xc = jax.lax.dot_general(x, c, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    d2 = xx - 2.0 * xc + cc[None, :]                    # (bn, K)
-    kpos = jax.lax.broadcasted_iota(jnp.int32, d2.shape, 1)
-    d2 = jnp.where(kpos < k_real, d2, jnp.inf)          # mask padded clusters
-    a_ref[...] = jnp.argmin(d2, axis=1).astype(jnp.int32)
-    d_ref[...] = jnp.maximum(d2.min(axis=1), 0.0)       # clamp fp cancellation
+from repro.kernels import platform
 
 
-def kmeans_assign_kernel(x: jax.Array, cent: jax.Array, *,
-                         k_real: int, block_n: int = 512,
-                         interpret: bool = True):
-    """x: (N_pad, D_pad) · cent: (K_pad, D_pad); N_pad % block_n == 0."""
-    N, D = x.shape
-    K = cent.shape[0]
+def _kernel(xt_ref, ct_ref, a_ref, d_ref, *, k_real: int):
+    xt = xt_ref[...].astype(jnp.float32)                # (D, bn)
+    ct = ct_ref[...].astype(jnp.float32)                # (D, K)
+    best_d = jnp.full((1, xt.shape[1]), jnp.inf, jnp.float32)
+    best_k = jnp.zeros((1, xt.shape[1]), jnp.int32)
+    for k in range(k_real):                             # static: K is small
+        diff = xt - ct[:, k:k + 1]
+        d2 = jnp.sum(diff * diff, axis=0, keepdims=True)  # (1, bn)
+        better = d2 < best_d
+        best_k = jnp.where(better, k, best_k)
+        best_d = jnp.where(better, d2, best_d)
+    a_ref[...] = best_k
+    d_ref[...] = best_d
+
+
+def kmeans_assign_kernel(xt: jax.Array, ct: jax.Array, *, k_real: int,
+                         block_n: int = 8192):
+    """xt: (D_pad, N_pad) · ct: (D_pad, K_pad) → ((1, N_pad) int32,
+    (1, N_pad) f32); D_pad % 8 == 0, N_pad % block_n == 0,
+    block_n % 128 == 0."""
+    D, N = xt.shape
+    K = ct.shape[1]
     kernel = functools.partial(_kernel, k_real=k_real)
     return pl.pallas_call(
         kernel,
         grid=(N // block_n,),
         in_specs=[
-            pl.BlockSpec((block_n, D), lambda i: (i, 0)),
-            pl.BlockSpec((K, D), lambda i: (0, 0)),
+            pl.BlockSpec((D, block_n), lambda i: (0, i)),
+            pl.BlockSpec((D, K), lambda i: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
+            pl.BlockSpec((1, block_n), lambda i: (0, i)),
+            pl.BlockSpec((1, block_n), lambda i: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((N,), jnp.int32),
-            jax.ShapeDtypeStruct((N,), jnp.float32),
+            jax.ShapeDtypeStruct((1, N), jnp.int32),
+            jax.ShapeDtypeStruct((1, N), jnp.float32),
         ],
-        interpret=interpret,
-    )(x, cent)
+        interpret=platform.interpret(),
+    )(xt, ct)

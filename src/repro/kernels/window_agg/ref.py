@@ -1,7 +1,8 @@
 """Pure-jnp oracle for the sliding-window aggregation kernel.
 
-Mirrors repro.pipeline.operators._window_agg semantics: causal window of
-``window`` rows (clamped at the start), same-length output.
+Same semantics as repro.pipeline.operators._window_agg: causal window of
+``window`` rows (clamped at the start), same-length output — computed the
+plainest way, independent of both the kernel and the operator.
 """
 
 from __future__ import annotations
@@ -12,21 +13,22 @@ import jax.numpy as jnp
 
 def window_agg_ref(x: jax.Array, *, window: int, agg: str = "mean"
                    ) -> jax.Array:
-    """x: (S, C) → (S, C); causal window [t-w+1, t] clamped at 0."""
+    """x: (S, C) → (S, C); causal window [t-w+1, t] clamped at 0: the w
+    shifted copies of x, stacked and reduced."""
     n = x.shape[0]
     w = max(1, min(window, n))
     xf = x.astype(jnp.float32)
-    if agg in ("mean", "sum"):
-        c = jnp.concatenate([jnp.zeros((1,) + x.shape[1:], jnp.float32),
-                             jnp.cumsum(xf, axis=0)], axis=0)
-        lo = jnp.maximum(jnp.arange(n) - w + 1, 0)
-        hi = jnp.arange(n) + 1
-        s = jnp.take(c, hi, axis=0) - jnp.take(c, lo, axis=0)
-        out = s if agg == "sum" else s / (hi - lo).astype(jnp.float32)[:, None]
-    elif agg == "max":
-        xpad = jnp.pad(xf, [(w - 1, 0)] + [(0, 0)] * (x.ndim - 1),
-                       mode="edge")
-        out = jnp.stack([xpad[i:i + n] for i in range(w)]).max(axis=0)
+    fill = -jnp.inf if agg == "max" else 0.0
+    xpad = jnp.pad(xf, [(w - 1, 0)] + [(0, 0)] * (x.ndim - 1),
+                   constant_values=fill)
+    stk = jnp.stack([xpad[i:i + n] for i in range(w)])
+    if agg == "max":
+        out = stk.max(axis=0)
+    elif agg in ("mean", "sum"):
+        out = stk.sum(axis=0)
+        if agg == "mean":
+            cnt = jnp.minimum(jnp.arange(1, n + 1), w).astype(jnp.float32)
+            out = out / cnt[:, None]
     else:
         raise ValueError(agg)
     return out.astype(x.dtype)

@@ -1,17 +1,21 @@
 """Sliding-window aggregation — Pallas TPU kernel.
 
 The hot loop of the paper's streaming services (window_agg / anomaly /
-summarize over tuple streams, §3.1). Memory-bound: each input row is read
-O(1) times, not O(window):
+summarize over tuple streams, §3.1). Memory-bound, so the layout is
+lane-dense: the wrapper hands the kernel the stream transposed,
+``(channels, time)``, with time on the 128 lanes and the handful of
+channels of the paper's tuple model on the sublanes.
 
-  * **sum/mean** — per-block inclusive cumulative sum plus the *previous*
-    block mapped in as a second view of the same operand (overlapping
-    BlockSpec index_map) → out[t] = cum[t] − cum[t−w], all in VMEM.
-  * **max** — w shifted maxima over the [prev ‖ cur] concatenation
-    (w ≤ block_s; the wrapper enforces/falls back).
+Per grid step the kernel holds one block of time and the block before it
+(a second view of the same operand through an overlapping ``BlockSpec``),
+and reduces each window by **binary doubling** along the lanes:
+``P_{2k}[t] = op(P_k[t], P_k[t-k])`` with ``pltpu.roll`` shifts, combined
+over the set bits of ``w``. That is O(log w) vector ops per element for
+sum, mean and max alike, with no prefix sum (Mosaic has no ``cumsum``)
+and no gather.
 
-Grid: one step per sequence block; channel dim rides whole (streams are
-narrow: a handful of float columns per the paper's tuple model).
+Grid: one step per time block; ``window ≤ block_s`` (the wrapper makes
+the block at least as long as the window).
 """
 
 from __future__ import annotations
@@ -21,52 +25,48 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import platform
+
+
+def _window_reduce(both: jax.Array, window: int, op) -> jax.Array:
+    """``out[:, t] = op(both[:, t-window+1 .. t])`` along the lanes; exact
+    for every ``t >= window - 1`` (lower lanes hold wrapped values)."""
+    acc = None
+    p, k, off = both, 1, 0
+    while True:
+        if window & k:
+            term = p if off == 0 else pltpu.roll(p, off, 1)
+            acc = term if acc is None else op(acc, term)
+            off += k
+        if 2 * k > window:
+            return acc
+        p = op(p, pltpu.roll(p, k, 1))
+        k *= 2
 
 
 def _kernel(prev_ref, cur_ref, o_ref, *, window: int, agg: str,
             block_s: int):
     i = pl.program_id(0)
-    prev = prev_ref[...].astype(jnp.float32)    # (bs, C) block i-1 (or junk at i=0)
-    cur = cur_ref[...].astype(jnp.float32)      # (bs, C) block i
-    prev = jnp.where(i > 0, prev, 0.0 if agg != "max" else -jnp.inf)
-    both = jnp.concatenate([prev, cur], axis=0)  # (2bs, C)
-
-    if agg in ("sum", "mean"):
-        cum = jnp.cumsum(both, axis=0)
-        hi = cum[block_s:]                       # inclusive cum at cur rows
-        # exclusive cum w rows back, clamped into the 2-block span
-        t_global = i * block_s + jax.lax.broadcasted_iota(
-            jnp.int32, (block_s,), 0)
-        lo_global = jnp.maximum(t_global - window + 1, 0)
-        lo_local = lo_global - (i - 1) * block_s  # index into `both`
-        lo_local = jnp.clip(lo_local, 0, 2 * block_s - 1)
-        zero = jnp.zeros((1, both.shape[1]), jnp.float32)
-        cum_ex = jnp.concatenate([zero, cum], axis=0)  # cum_ex[j] = sum(<j)
-        lo_vals = jnp.take(cum_ex, lo_local, axis=0)
-        s = hi - lo_vals
-        if agg == "mean":
-            cnt = (t_global - lo_global + 1).astype(jnp.float32)
-            s = s / cnt[:, None]
-        o_ref[...] = s.astype(o_ref.dtype)
-    else:  # max
-        t_global = i * block_s + jax.lax.broadcasted_iota(
-            jnp.int32, (block_s,), 0)
-        acc = jnp.full_like(cur, -jnp.inf)
-        for j in range(window):                 # static unroll, w small
-            idx = block_s - j + jax.lax.broadcasted_iota(
-                jnp.int32, (block_s,), 0)       # cur row t ↔ both[bs + t - j]
-            shifted = jnp.take(both, jnp.clip(idx, 0, 2 * block_s - 1),
-                               axis=0)
-            use = (t_global - j) >= 0           # clamp at sequence start
-            acc = jnp.where(use[:, None], jnp.maximum(acc, shifted), acc)
-        o_ref[...] = acc.astype(o_ref.dtype)
+    fill = -jnp.inf if agg == "max" else 0.0
+    prev = prev_ref[...].astype(jnp.float32)     # (C, bs) block i-1
+    cur = cur_ref[...].astype(jnp.float32)       # (C, bs) block i
+    prev = jnp.where(i > 0, prev, fill)          # nothing before t = 0
+    both = jnp.concatenate([prev, cur], axis=1)  # (C, 2bs)
+    op = jnp.maximum if agg == "max" else jnp.add
+    out = _window_reduce(both, window, op)[:, block_s:]
+    if agg == "mean":
+        t = i * block_s + jax.lax.broadcasted_iota(jnp.int32, out.shape, 1)
+        out = out / jnp.minimum(t + 1, window).astype(jnp.float32)
+    o_ref[...] = out.astype(o_ref.dtype)
 
 
-def window_agg_kernel(x: jax.Array, *, window: int, agg: str = "mean",
-                      block_s: int = 256, interpret: bool = True
-                      ) -> jax.Array:
-    """x: (S_pad, C_pad), S_pad % block_s == 0, window ≤ block_s."""
-    S, C = x.shape
+def window_agg_kernel(xt: jax.Array, *, window: int, agg: str = "mean",
+                      block_s: int = 2048) -> jax.Array:
+    """xt: (C_pad, S_pad) time-major-on-lanes, C_pad % 8 == 0,
+    S_pad % block_s == 0, block_s % 128 == 0, window ≤ block_s."""
+    C, S = xt.shape
     if window > block_s:
         raise ValueError("window must be ≤ block_s")
     kernel = functools.partial(_kernel, window=window, agg=agg,
@@ -76,11 +76,10 @@ def window_agg_kernel(x: jax.Array, *, window: int, agg: str = "mean",
         grid=(S // block_s,),
         in_specs=[
             # previous block (index clamped at 0; masked inside the kernel)
-            pl.BlockSpec((block_s, C),
-                         lambda i: (jnp.maximum(i - 1, 0), 0)),
-            pl.BlockSpec((block_s, C), lambda i: (i, 0)),
+            pl.BlockSpec((C, block_s), lambda i: (0, jnp.maximum(i - 1, 0))),
+            pl.BlockSpec((C, block_s), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((block_s, C), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((S, C), x.dtype),
-        interpret=interpret,
-    )(x, x)
+        out_specs=pl.BlockSpec((C, block_s), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((C, S), xt.dtype),
+        interpret=platform.interpret(),
+    )(xt, xt)

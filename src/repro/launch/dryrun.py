@@ -1,9 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                           + " --xla_force_host_platform_device_count=512").strip()
-# ^ MUST precede every other import: jax locks the device count on first
-#   init, and the multi-pod dry-run needs 512 placeholder host devices.
-
 """Multi-pod dry-run harness (deliverable e).
 
 For every (architecture × input shape × mesh) cell:
@@ -24,10 +18,15 @@ Usage:
 
 ``--all`` runs each cell in a fresh subprocess (compile state isolation;
 one cell crashing doesn't take the sweep down).
+
+A CPU-only tool: :func:`main` pins the ``cpu`` platform with 512
+placeholder host devices before anything touches a backend, so on a
+machine with an accelerator it never takes the chip.
 """
 
 import argparse
 import json
+import os
 import subprocess
 import sys
 import time
@@ -36,8 +35,6 @@ from typing import Any, Dict, Tuple
 import numpy as np
 
 import jax
-
-from repro.distributed.compat import set_mesh
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -181,7 +178,7 @@ def run_cell(arch: str, shape: str, mesh_kind: str,
                     return step(state, batch)
 
             t0 = time.time()
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 lowered = jax.jit(
                     fn, in_shardings=(state_sh, batch_sh),
                     out_shardings=(state_sh, None)
@@ -229,7 +226,7 @@ def run_cell(arch: str, shape: str, mesh_kind: str,
             if info["kind"] == "decode" and donate_caches:
                 donate = (3,)
             t0 = time.time()
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 lowered = jax.jit(
                     fn, in_shardings=tuple(shardings),
                     donate_argnums=donate).lower(*args)
@@ -241,8 +238,6 @@ def run_cell(arch: str, shape: str, mesh_kind: str,
 
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax < 0.5 wraps it per-program
-        ca = ca[0] if ca else {}
     txt = compiled.as_text()
     hlo = hlo_analysis.analyze(txt, chips_per_pod=CHIPS_PER_POD)
 
@@ -310,7 +305,15 @@ def run_cell(arch: str, shape: str, mesh_kind: str,
 # CLI
 # ---------------------------------------------------------------------------
 
+def _pin_host_platform() -> None:
+    """CPU backend only, with enough placeholder devices for 2 pods. Must
+    run before the first backend use: jax fixes both at initialisation."""
+    jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_num_cpu_devices", 512)
+
+
 def main(argv=None) -> int:
+    _pin_host_platform()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCHS)
     ap.add_argument("--shape", choices=list(SHAPES))

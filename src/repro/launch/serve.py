@@ -17,6 +17,7 @@ import numpy as np
 import jax
 
 from repro.configs import ARCHS, get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import frontends
 from repro.models import model as model_lib
 from repro.serve.engine import EngineConfig, Request, ServeEngine
@@ -45,6 +46,7 @@ def main(argv=None) -> int:
     ap.add_argument("--policy", default="all",
                     choices=("fcfs", "eft", "edf", "all"))
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch, smoke=True)
     params = model_lib.init(cfg, jax.random.PRNGKey(0))

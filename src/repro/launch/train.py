@@ -20,6 +20,7 @@ import sys
 
 from repro.configs import ARCHS, get_config
 from repro.data.loader import LoaderConfig, Prefetcher, TokenBatchLoader
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import frontends
 from repro.train.optimizer import OptConfig
 from repro.train.trainer import Trainer, TrainerConfig
@@ -59,6 +60,7 @@ def main(argv=None) -> int:
     ap.add_argument("--inject-failure-at", type=int, default=0,
                     help="simulate a worker death at this step (0 = off)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     opt = OptConfig(name=args.optimizer, lr=args.lr,

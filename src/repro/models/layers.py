@@ -24,7 +24,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.compat import shard_map
 from repro.models.config import ModelConfig
 
 Params = Dict[str, Any]
@@ -264,7 +263,7 @@ def sharded_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         out = acc_g / jnp.maximum(lsum_g, 1e-30)[..., None]
         return out.reshape(q_l.shape[0], 1, Hq, D).astype(q_l.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P_(b_rule, None, None, None),
                   P_(b_rule, cap_rule, None, None),

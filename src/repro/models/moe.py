@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
-from repro.distributed.compat import shard_map
 from repro.models.config import ModelConfig
 from repro.models.layers import apply_mlp, init_mlp
 
@@ -239,7 +238,7 @@ def apply_moe_shard_map(cfg: ModelConfig, p: Params, x: jax.Array, rules
             aux = {k: jax.lax.pmean(v, dp_axes) for k, v in sorted(aux.items())}
         return y.reshape(Bl, Sl, d), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, P_(), wi_spec, wi_spec, wo_spec, shared_specs),
         out_specs=(x_spec, {k: P_() for k in

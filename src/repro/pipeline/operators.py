@@ -27,14 +27,9 @@ from __future__ import annotations
 import functools
 from typing import Any, Callable, Dict, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
-
-try:  # device backends need jax; host backends must work without it
-    import jax.numpy as jnp
-    _HAS_JAX = True
-except Exception:  # pragma: no cover
-    jnp = None
-    _HAS_JAX = False
 
 
 # ---------------------------------------------------------------------------
@@ -84,30 +79,44 @@ def _summarize(xp, x):
     return xp.stack([mean, std, lo, hi, med])
 
 
+def _shift_down(xp, x, k: int, fill: float):
+    """Row t of the result is x[t-k]; the first k rows (0 < k < n) are
+    ``fill``."""
+    head = xp.full((k,) + x.shape[1:], fill, dtype=x.dtype)
+    return xp.concatenate([head, x[:x.shape[0] - k]], axis=0)
+
+
 def _window_agg(xp, x, *, window: int = 8, agg: str = "mean"):
     """Sliding-window aggregate along axis 0 (same-length, causal).
 
-    Implemented with cumulative sums (mean/sum) or a strided stack (max) —
-    both shape-static. Window w uses rows [t-w+1, t] clamped at 0.
+    Window w uses rows [t-w+1, t] clamped at 0. Windows are reduced by
+    binary doubling, ``P_2k[t] = op(P_k[t], P_k[t-k])`` combined over the
+    set bits of w: O(log w) shape-static passes, the same additions in the
+    same order on both backends (a prefix-sum difference would round
+    differently per backend and grow its error with the stream length).
     """
     n = x.shape[0]
     w = max(1, min(window, n))
     if agg in ("mean", "sum"):
-        c = xp.cumsum(x, axis=0)
-        zeros = xp.zeros((1,) + x.shape[1:], dtype=x.dtype)
-        c = xp.concatenate([zeros, c], axis=0)          # c[i] = sum of x[:i]
-        lo = xp.maximum(xp.arange(n) - w + 1, 0)
-        hi = xp.arange(n) + 1
-        s = xp.take(c, hi, axis=0) - xp.take(c, lo, axis=0)
-        if agg == "sum":
-            return s
-        return s / (hi - lo).astype(x.dtype)[:, None]
-    if agg == "max":
-        pads = [(w - 1, 0)] + [(0, 0)] * (x.ndim - 1)
-        xpad = xp.pad(x, pads, mode="edge")
-        stk = xp.stack([xpad[i:i + n] for i in range(w)])
-        return stk.max(axis=0)
-    raise ValueError(f"unknown agg {agg!r}")
+        op, fill = xp.add, 0.0
+    elif agg == "max":
+        op, fill = xp.maximum, -np.inf
+    else:
+        raise ValueError(f"unknown agg {agg!r}")
+    acc, p, k, off = None, x, 1, 0
+    while True:
+        if w & k:
+            term = p if off == 0 else _shift_down(xp, p, off, fill)
+            acc = term if acc is None else op(acc, term)
+            off += k
+        if 2 * k > w:
+            break
+        p = op(p, _shift_down(xp, p, k, fill))
+        k *= 2
+    if agg != "mean":
+        return acc
+    cnt = xp.minimum(xp.arange(1, n + 1), w).astype(x.dtype)
+    return acc / cnt.reshape((n,) + (1,) * (x.ndim - 1))
 
 
 def _anomaly(xp, x, *, window: int = 16, z: float = 3.0):
@@ -158,32 +167,45 @@ def _pca(xp, x, *, k: int = 2, iters: int = 16):
     return xc @ q
 
 
+def _assign(xp, x, cent):
+    """Nearest centroid per row, and the (n, k) squared distances."""
+    d2 = ((x[:, None, :] - cent[None, :, :]) ** 2).sum(-1)
+    return xp.argmin(d2, axis=1), d2
+
+
 def _kmeans_step(xp, x, cent):
-    d2 = ((x[:, None, :] - cent[None, :, :]) ** 2).sum(-1)    # (n, k)
-    assign = xp.argmin(d2, axis=1)
+    assign, _ = _assign(xp, x, cent)
     onehot = (assign[:, None] == xp.arange(cent.shape[0])[None, :]).astype(x.dtype)
     cnt = xp.maximum(onehot.sum(0), 1.0)
     new = (onehot.T @ x) / cnt[:, None]
     # keep empty clusters where they were
-    new = xp.where((onehot.sum(0) > 0)[:, None], new, cent)
-    return new, assign, d2
+    return xp.where((onehot.sum(0) > 0)[:, None], new, cent)
 
 
 def _kmeans_init(xp, x, k: int):
-    """Deterministic spread init: evenly-spaced rows of the sorted-by-norm x."""
+    """Deterministic spread init: k evenly-spaced rows in stream order.
+
+    Picking by position keeps the init continuous in x: a choice by rank
+    (e.g. rows at evenly-spaced ranks of the row norms) jumps to another
+    row when two norms swap order, which last-digit differences between
+    backends do at scale, and Lloyd's iterations then settle elsewhere."""
     n = x.shape[0]
-    order = xp.argsort((x * x).sum(-1))
-    pick = xp.take(order, (xp.arange(k) * max(n // k, 1)) % n)
-    return xp.take(x, pick, axis=0)
+    return xp.take(x, (xp.arange(k) * max(n // k, 1)) % n, axis=0)
+
+
+def _lloyd(xp, x, cent, iters: int):
+    """``iters`` Lloyd updates from ``cent``; returns (centroids, the
+    assignments to them, inertia)."""
+    for _ in range(iters):
+        cent = _kmeans_step(xp, x, cent)
+    assign, d2 = _assign(xp, x, cent)
+    inertia = xp.take_along_axis(d2, assign[:, None], axis=1).sum()
+    return cent, assign, inertia
 
 
 def _kmeans(xp, x, *, k: int = 4, iters: int = 10):
     """Lloyd's k-means; returns (centroids, assignments, inertia)."""
-    cent = _kmeans_init(xp, x, k)
-    for _ in range(iters):
-        cent, assign, d2 = _kmeans_step(xp, x, cent)
-    inertia = xp.take_along_axis(d2, assign[:, None], axis=1).sum()
-    return cent, assign, inertia
+    return _lloyd(xp, x, _kmeans_init(xp, x, k), iters)
 
 
 def _sweep_clustering(xp, x, *, ks: Tuple[int, ...] = (2, 3, 4, 6),
@@ -204,10 +226,7 @@ def _sweep_clustering(xp, x, *, ks: Tuple[int, ...] = (2, 3, 4, 6),
 def _train_cluster(xp, x, cent, *, iters: int = 20):
     """Refine a clustering model from given centroids (paper's
     'train clustering model' node consuming kmeans/sweep output)."""
-    for _ in range(iters):
-        cent, assign, d2 = _kmeans_step(xp, x, cent)
-    inertia = xp.take_along_axis(d2, assign[:, None], axis=1).sum()
-    return cent, assign, inertia
+    return _lloyd(xp, x, cent, iters)
 
 
 def _linreg(xp, x, *, target_col: int = 0, ridge: float = 1e-6):
@@ -300,14 +319,20 @@ def host_backend(op: str) -> Callable:
 def device_backend(op: str) -> Callable:
     """Device (jax.numpy) implementation of ``op``.
 
-    kmeans-family ops route through the Pallas kernel wrapper when the
-    shapes are tile-friendly (see repro.kernels.kmeans.ops); everything else
-    is pure jnp. All are jit-compatible.
+    Every device backend is the generic operator over ``jax.numpy``,
+    dispatched op by op; none calls a Pallas kernel yet
+    (repro.kernels holds kernels for window_agg and the kmeans
+    assignment step, checked against these operators by the tests).
+    Matmuls run at full float32 precision: a TPU's default is a single
+    bfloat16 pass, which would break parity with the host backend.
     """
-    if not _HAS_JAX:  # pragma: no cover
-        raise RuntimeError("jax unavailable; device backend disabled")
     fn = _GENERIC[op]
-    return functools.partial(fn, jnp)
+
+    def run(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return fn(jnp, *args, **kwargs)
+
+    return run
 
 
 def backends(op: str) -> Dict[str, Callable]:

@@ -4,88 +4,10 @@ NOTE: no XLA_FLAGS here on purpose — smoke tests and benches must see the
 real (single) CPU device; only launch.dryrun (and subprocess-based
 distributed tests) request placeholder device counts, in their own
 processes.
-
-If `hypothesis` is not installed (it is a test-only extra; some execution
-environments cannot pip install), a minimal deterministic fallback is
-registered in ``sys.modules`` before collection so the property-test
-modules still import and run: ``@given`` draws a fixed number of
-seeded-pseudo-random examples per strategy. Install the real package
-(``pip install -e .[test]``) for shrinking, the example database, and real
-coverage of the strategy space.
 """
-
-import importlib.util
-import sys
 
 import numpy as np
 import pytest
-
-
-def _install_hypothesis_fallback() -> None:
-    import functools
-    import inspect
-    import random
-    import types
-
-    st_mod = types.ModuleType("hypothesis.strategies")
-
-    class _Strategy:
-        def __init__(self, sample):
-            self.sample = sample  # rng -> value
-
-    def integers(min_value, max_value):
-        return _Strategy(lambda r: r.randint(min_value, max_value))
-
-    def floats(min_value, max_value):
-        return _Strategy(lambda r: r.uniform(min_value, max_value))
-
-    def booleans():
-        return _Strategy(lambda r: bool(r.randint(0, 1)))
-
-    def sampled_from(elements):
-        elements = list(elements)
-        return _Strategy(lambda r: r.choice(elements))
-
-    st_mod.integers = integers
-    st_mod.floats = floats
-    st_mod.booleans = booleans
-    st_mod.sampled_from = sampled_from
-
-    hyp_mod = types.ModuleType("hypothesis")
-
-    def settings(**kw):
-        def deco(fn):
-            fn._fallback_max_examples = kw.get("max_examples", 10)
-            return fn
-
-        return deco
-
-    def given(**strategies):
-        def deco(fn):
-            @functools.wraps(fn)
-            def wrapper(*args, **kwargs):
-                n = getattr(wrapper, "_fallback_max_examples", 10)
-                rng = random.Random(0xA4D5)
-                for _ in range(n):
-                    drawn = {k: s.sample(rng) for k, s in strategies.items()}
-                    fn(*args, **kwargs, **drawn)
-
-            # hide the drawn parameters from pytest's fixture resolution
-            del wrapper.__wrapped__
-            wrapper.__signature__ = inspect.Signature()
-            return wrapper
-
-        return deco
-
-    hyp_mod.given = given
-    hyp_mod.settings = settings
-    hyp_mod.strategies = st_mod
-    sys.modules["hypothesis"] = hyp_mod
-    sys.modules["hypothesis.strategies"] = st_mod
-
-
-if importlib.util.find_spec("hypothesis") is None:
-    _install_hypothesis_fallback()
 
 
 @pytest.fixture(scope="session")

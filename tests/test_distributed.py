@@ -31,9 +31,10 @@ def test_strategy_and_param_specs_divisibility():
         from jax.sharding import PartitionSpec as P
         from repro.configs import get_config
         from repro.distributed import sharding as sh
+        from repro.launch.mesh import make_mesh
         from repro.models import model as M
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         # musicgen: 6 heads % 4 != 0 → attention replicated, d_ff sharded
         cfg = get_config("musicgen-medium", smoke=True)
         rules = sh.strategy_for(cfg, mesh)
@@ -64,16 +65,16 @@ def test_strategy_and_param_specs_divisibility():
 def test_hierarchical_psum_equals_flat():
     out = run_sub("""
         import numpy as np, jax, jax.numpy as jnp
-        from repro.distributed.compat import shard_map
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
         from repro.distributed.collectives import hierarchical_psum
-        mesh = jax.make_mesh((2, 4), ("pod", "data"))
+        mesh = make_mesh((2, 4), ("pod", "data"))
         x = jnp.asarray(np.random.default_rng(0).normal(0, 1, (8, 33)),
                         jnp.float32)
-        f1 = shard_map(lambda v: jax.lax.psum(v, ("pod", "data")),
+        f1 = jax.shard_map(lambda v: jax.lax.psum(v, ("pod", "data")),
                            mesh=mesh, in_specs=P(), out_specs=P(),
                            check_vma=False)(x)
-        f2 = shard_map(lambda v: hierarchical_psum(v), mesh=mesh,
+        f2 = jax.shard_map(lambda v: hierarchical_psum(v), mesh=mesh,
                            in_specs=P(), out_specs=P(), check_vma=False)(x)
         assert float(jnp.abs(f1 - f2).max()) < 1e-4
         print("OK")
@@ -84,19 +85,19 @@ def test_hierarchical_psum_equals_flat():
 def test_int8_allreduce_accuracy_and_error_feedback():
     out = run_sub("""
         import numpy as np, jax, jax.numpy as jnp
-        from repro.distributed.compat import shard_map
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
         from repro.distributed.collectives import int8_allreduce
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         vals = jnp.asarray(np.random.default_rng(1).normal(0, 1, (8, 1000)),
                            jnp.float32)
-        ref = shard_map(lambda v: jax.lax.pmean(v, "data"), mesh=mesh,
+        ref = jax.shard_map(lambda v: jax.lax.pmean(v, "data"), mesh=mesh,
                             in_specs=P("data"), out_specs=P("data"),
                             check_vma=False)(vals)
         def comp(v, e):
             out, e2 = int8_allreduce(v[0], axis="data", error=e[0])
             return out[None], e2[None]
-        out, err = shard_map(comp, mesh=mesh,
+        out, err = jax.shard_map(comp, mesh=mesh,
                                  in_specs=(P("data"), P("data")),
                                  out_specs=(P("data"), P("data")),
                                  check_vma=False)(vals, jnp.zeros_like(vals))
@@ -113,7 +114,7 @@ def test_sharded_train_step_matches_single_device():
     mesh, must produce the same loss trajectory as unsharded execution."""
     out = run_sub("""
         import numpy as np, jax, jax.numpy as jnp
-        from repro.distributed.compat import set_mesh
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config
         from repro.distributed import sharding as sh
@@ -132,7 +133,7 @@ def test_sharded_train_step_matches_single_device():
         s1, m1 = jax.jit(step)(state, batch)
 
         # sharded
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         rules = sh.strategy_for(cfg, mesh)
         with sh.logical_axis_rules(rules):
             st_sh = jax.tree_util.tree_map(
@@ -144,7 +145,7 @@ def test_sharded_train_step_matches_single_device():
             def fn(s, b):
                 with sh.logical_axis_rules(rules):
                     return step(s, b)
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 s2, m2 = jax.jit(fn, in_shardings=(st_sh, b_sh),
                                  out_shardings=(st_sh, None))(state, batch)
         assert abs(float(m1["loss"]) - float(m2["loss"])) < 1e-4, \\

@@ -20,7 +20,7 @@ def test_hlo_analysis_on_synthetic_scan():
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp, json
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.distributed.compat import set_mesh
+        from repro.launch.mesh import make_mesh
         from repro.launch import hlo_analysis as H
 
         def f(x, w):
@@ -29,8 +29,8 @@ def test_hlo_analysis_on_synthetic_scan():
             out, _ = jax.lax.scan(body, x, w)
             return out.sum()
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
-        with set_mesh(mesh):
+        mesh = make_mesh((2, 4), ("data", "model"))
+        with jax.set_mesh(mesh):
             comp = jax.jit(f, in_shardings=(
                 NamedSharding(mesh, P("data", None)),
                 NamedSharding(mesh, P(None, None, "model")))).lower(

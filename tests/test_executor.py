@@ -69,3 +69,35 @@ def test_zero_duration_predecessor_executes_before_successor():
     rep = Executor(pool).execute(g, sched)
     assert [r.task for r in rep.runs] == ["z_head", "a_tail"]
     assert float(rep.outputs["a_tail"]) == 6.0
+
+
+def _one_task_dag(backends):
+    from repro.core.dag import PipelineDAG, Task
+    g = PipelineDAG("one")
+    g.add_task(Task("t", "sql_transform", work=1.0, backends=backends))
+    return g
+
+
+def test_missing_device_backend_raises():
+    """A device PE whose task has no device backend is an error, not a
+    silent run on the host."""
+    pool = paper_pool(n_arm=0, n_volta=0, n_xeon=1, n_v100=0, n_alveo=0)
+    g = _one_task_dag({"host": lambda: np.float32(1.0)})
+    sched = schedule(g, pool, CostModel(), policy="eft")
+    with pytest.raises(ValueError, match="needs backend 'device'"):
+        Executor(pool, backend_of=lambda pe: "device").execute(g, sched)
+
+
+def test_device_error_at_block_until_ready_propagates():
+    """With async dispatch a device fault surfaces when the result is
+    awaited; the executor must not swallow it there."""
+
+    class _FaultsOnSync:
+        def block_until_ready(self):
+            raise RuntimeError("device fault")
+
+    pool = paper_pool(n_arm=0, n_volta=0, n_xeon=1, n_v100=0, n_alveo=0)
+    g = _one_task_dag({"device": lambda: _FaultsOnSync()})
+    sched = schedule(g, pool, CostModel(), policy="eft")
+    with pytest.raises(RuntimeError, match="device fault"):
+        Executor(pool, backend_of=lambda pe: "device").execute(g, sched)

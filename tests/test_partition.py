@@ -327,7 +327,7 @@ def test_executor_partition_recomputes_only_cross_partition_subgraph():
     sched = Schedule(asg, pool, "manual")
     inj = FailureInjector([FailureEvent(2, "xeon0", "partition"),
                            FailureEvent(6, "xeon0", "heal")])
-    ex = Executor(pool)
+    ex = Executor(pool, backend_of=lambda pe: "host")  # host-only tasks
     rep1 = ex.execute(g, sched, injector=inj)
     # degraded mode: edge-local AND dc-local work both executed mid-cut
     assert [r.task for r in rep1.runs] == ["e0", "d0", "e1", "d1", "e3"]

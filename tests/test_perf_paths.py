@@ -27,7 +27,7 @@ def run_sub(code: str, devices: int = 8, timeout: int = 600) -> str:
 def test_moe_shard_map_matches_spmd():
     out = run_sub("""
         import numpy as np, jax, jax.numpy as jnp, dataclasses
-        from repro.distributed.compat import set_mesh
+        from repro.launch.mesh import make_mesh
         from repro.models.config import ModelConfig
         from repro.models import moe as moe_lib
         from repro.distributed import sharding as sh
@@ -43,11 +43,11 @@ def test_moe_shard_map_matches_spmd():
                         jnp.float32)
         y_ref, aux_ref = moe_lib.apply_moe_spmd(cfg, p, x)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rules = sh.strategy_for(cfg, mesh, moe_shard_map=True)
         assert rules.options["moe_shard_map"]
         with sh.logical_axis_rules(rules):
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 y, aux = jax.jit(lambda p_, x_: moe_lib.apply_moe_shard_map(
                     cfg, p_, x_, rules))(p, x)
         err = float(jnp.abs(y - y_ref).max())
@@ -63,7 +63,7 @@ def test_moe_shard_map_matches_spmd():
         y_ref2, _ = moe_lib.apply_moe_spmd(cfg2, p2, x)
         rules2 = sh.strategy_for(cfg2, mesh, moe_shard_map=True)
         with sh.logical_axis_rules(rules2):
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 y2, _ = jax.jit(lambda p_, x_: moe_lib.apply_moe_shard_map(
                     cfg2, p_, x_, rules2))(p2, x)
         err2 = float(jnp.abs(y2 - y_ref2).max())
@@ -76,7 +76,7 @@ def test_moe_shard_map_matches_spmd():
 def test_moe_shard_map_grad_flows():
     out = run_sub("""
         import numpy as np, jax, jax.numpy as jnp
-        from repro.distributed.compat import set_mesh
+        from repro.launch.mesh import make_mesh
         from repro.models.config import ModelConfig
         from repro.models import moe as moe_lib
         from repro.distributed import sharding as sh
@@ -87,7 +87,7 @@ def test_moe_shard_map_grad_flows():
         p = moe_lib.init_moe(cfg, jax.random.PRNGKey(0))
         x = jnp.asarray(np.random.default_rng(0).normal(0, 1, (4, 16, 32)),
                         jnp.float32)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rules = sh.strategy_for(cfg, mesh, moe_shard_map=True)
 
         def loss_sm(p_):
@@ -99,7 +99,7 @@ def test_moe_shard_map_grad_flows():
             return (y ** 2).mean() + 0.01 * aux["aux_loss"]
 
         with sh.logical_axis_rules(rules):
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 g1 = jax.jit(jax.grad(loss_sm))(p)
         g2 = jax.grad(loss_ref)(p)
         d = jax.tree_util.tree_map(
@@ -114,7 +114,7 @@ def test_moe_shard_map_grad_flows():
 def test_sharded_flash_decode_matches_baseline():
     out = run_sub("""
         import numpy as np, jax, jax.numpy as jnp
-        from repro.distributed.compat import set_mesh
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config
         from repro.models import model as M
         from repro.models import transformer as T
@@ -132,11 +132,11 @@ def test_sharded_flash_decode_matches_baseline():
                                   jnp.full((B,), S-1, jnp.int32), caches)
 
         # sharded flash-decode (cache capacity 32 over model=4)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rules = sh.strategy_for(cfg, mesh, decode_flash_shard=True)
         assert rules.rules["cache_cap"] == "model"
         with sh.logical_axis_rules(rules):
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 caches2 = T.init_caches(cfg, B, 32)
                 lg_p2, caches2 = jax.jit(
                     lambda pr, t, c: M.prefill(cfg, pr, t, c))(
@@ -155,7 +155,7 @@ def test_sharded_flash_decode_matches_baseline():
 def test_fsdp_strategy_matches_tp_loss():
     out = run_sub("""
         import numpy as np, jax, jax.numpy as jnp
-        from repro.distributed.compat import set_mesh
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config
         from repro.distributed import sharding as sh
@@ -171,7 +171,7 @@ def test_fsdp_strategy_matches_tp_loss():
         step = build_train_step(cfg, oc, remat=False)
         _, m_ref = jax.jit(step)(state, batch)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rules = sh.strategy_for(cfg, mesh, mode="fsdp")
         assert "ZeRO-3" in rules.notes
         with sh.logical_axis_rules(rules):
@@ -184,7 +184,7 @@ def test_fsdp_strategy_matches_tp_loss():
             def fn(s, b):
                 with sh.logical_axis_rules(rules):
                     return step(s, b)
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 _, m2 = jax.jit(fn, in_shardings=(st_sh, b_sh),
                                 out_shardings=(st_sh, None))(state, batch)
         assert abs(float(m_ref["loss"]) - float(m2["loss"])) < 1e-4

@@ -152,7 +152,8 @@ def test_vdc_availability_reserve_enforced_after_allocation():
 
 def test_reshard_on_current_devices():
     from jax.sharding import PartitionSpec as P
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     tree = {"w": np.ones((4, 4), np.float32)}
     out = el.reshard(tree, mesh, lambda leaf: P())
     assert np.asarray(out["w"]).sum() == 16
