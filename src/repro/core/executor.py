@@ -21,11 +21,13 @@ import time
 from typing import Any, Callable, Dict, List, Mapping, Optional, Tuple
 
 import jax
+import numpy as np
 
 from repro.core.cost_model import LearnedCostModel
 from repro.core.dag import PipelineDAG, Task
 from repro.core.resources import FRONTEND, ResourcePool
 from repro.core.schedulers import Schedule
+from repro.core.spans import span
 
 
 @dataclasses.dataclass
@@ -70,6 +72,17 @@ class ExecutionReport:
     def complete(self, dag: PipelineDAG) -> bool:
         """True iff every task of ``dag`` has a live output."""
         return all(t.name in self.outputs for t in dag.tasks)
+
+
+def _cross_bytes(args: Any, backend: str) -> int:
+    """Bytes of ``args`` that sit on the other side of the host/device
+    boundary from ``backend``: numpy leaves for the device backend, device
+    arrays for the host one, each counted once whether the backend reads
+    it or passes it through. The executor moves nothing itself, and a
+    backend that uses an input twice may copy it twice."""
+    other = (np.ndarray, np.generic) if backend == "device" else jax.Array
+    return sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(args)
+               if isinstance(x, other))
 
 
 class Executor:
@@ -184,13 +197,19 @@ class Executor:
             if a.pe in dead or not all(_fetchable(p) for p in preds):
                 skipped.append(task.name)
                 continue
-            args = [outputs[p.name] for p in preds]
-            if task.name in inputs:
-                args = [inputs[task.name]] + args
-            kind, fn = self._resolve(task, a.pe)
-            t0 = time.perf_counter()
-            out = jax.block_until_ready(fn(*args, **task.params))
-            dt = (time.perf_counter() - t0) * slow.get(a.pe, 1.0)
+            with span("executor.task", task=task.name, op=task.op,
+                      pe=a.pe) as sp:
+                args = [outputs[p.name] for p in preds]
+                if task.name in inputs:
+                    args = [inputs[task.name]] + args
+                kind, fn = self._resolve(task, a.pe)
+                sp.set_metadata(backend=kind,
+                                cross_bytes=_cross_bytes(args, kind))
+                t0 = time.perf_counter()
+                out = fn(*args, **task.params)
+                with span("executor.wait", task=task.name):
+                    out = jax.block_until_ready(out)
+                dt = (time.perf_counter() - t0) * slow.get(a.pe, 1.0)
             outputs[task.name] = out
             copies[task.name] = {a.pe}
             for p in preds:

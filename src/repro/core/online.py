@@ -75,6 +75,7 @@ from repro.core.resources import ResourcePool
 from repro.core.sanitize import ScheduleSanitizer
 from repro.core.sanitize import enabled as _sanitize_enabled
 from repro.core.sanitize import validate_curve as _validate_curve
+from repro.core.spans import span
 from repro.core import vos as vos_mod
 from repro.core.schedulers import (Assignment, OnlineEngine, Schedule,
                                    make_policy_run)
@@ -246,23 +247,24 @@ class OnlineDriver:
         streaming counterpart of ``schedule_vos(curves=...)``; the curve is
         registered before admission so the admission gate's floor is exact
         for this instance."""
-        arrival_t = float(arrival_t)
-        if curve is not None:
-            add = getattr(self.policy, "add_curve", None)
-            if add is None:
-                raise ValueError(
-                    f"submit(curve=...) needs the 'vos' policy, not "
-                    f"{self.policy_name!r}")
-            add(dag, curve)
-        if curve is not None and self.sanitizer is not None:
-            _validate_curve(curve, name=dag.name)
-        heapq.heappush(self._pending, (arrival_t, self._seq, dag))
-        if self._gate is not None:
-            heapq.heappush(self._gate,
-                           (self.policy.arrival_floor(arrival_t, dag),
-                            arrival_t, self._seq, dag))
-        self._seq += 1
-        self._n_pending += 1
+        with span("planner.submit", instance=dag.name, tasks=len(dag)):
+            arrival_t = float(arrival_t)
+            if curve is not None:
+                add = getattr(self.policy, "add_curve", None)
+                if add is None:
+                    raise ValueError(
+                        f"submit(curve=...) needs the 'vos' policy, not "
+                        f"{self.policy_name!r}")
+                add(dag, curve)
+            if curve is not None and self.sanitizer is not None:
+                _validate_curve(curve, name=dag.name)
+            heapq.heappush(self._pending, (arrival_t, self._seq, dag))
+            if self._gate is not None:
+                heapq.heappush(self._gate,
+                               (self.policy.arrival_floor(arrival_t, dag),
+                                arrival_t, self._seq, dag))
+            self._seq += 1
+            self._n_pending += 1
 
     @property
     def pending(self) -> int:
@@ -563,26 +565,27 @@ class OnlineDriver:
         """One event: admit due arrivals, place one task. None when no
         placeable work remains (drained, or only far-future arrivals that
         were all admitted — impossible — so: fully drained)."""
-        if self._n_pending:
-            self._admit_due()
-        eng = self.eng
-        if eng.done():
-            return None
-        tid = self.policy.step()
-        self.n_events += 1
-        a = eng.assignments[-1]
-        if self.sanitizer is not None:
-            self.sanitizer.after_step(a)
-        inst = self.instances[self._inst_of[tid]]
-        inst.remaining -= 1
-        if a.finish > inst.finish:
-            inst.finish = a.finish
-        if inst.remaining == 0:
-            inst.completed = True
-            self._live -= 1
-            self.completions.append((inst.name, inst.finish))
-            self._retire(inst)
-        return a
+        with span("planner.step"):
+            if self._n_pending:
+                self._admit_due()
+            eng = self.eng
+            if eng.done():
+                return None
+            tid = self.policy.step()
+            self.n_events += 1
+            a = eng.assignments[-1]
+            if self.sanitizer is not None:
+                self.sanitizer.after_step(a)
+            inst = self.instances[self._inst_of[tid]]
+            inst.remaining -= 1
+            if a.finish > inst.finish:
+                inst.finish = a.finish
+            if inst.remaining == 0:
+                inst.completed = True
+                self._live -= 1
+                self.completions.append((inst.name, inst.finish))
+                self._retire(inst)
+            return a
 
     def _retire(self, inst: InstanceState) -> None:
         # placed tasks' transfer plans are never consulted again — free the

@@ -24,12 +24,13 @@ ingest, window aggregation, cleaning, export.
 
 from __future__ import annotations
 
-import functools
 from typing import Any, Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.core.spans import span
 
 
 # ---------------------------------------------------------------------------
@@ -311,9 +312,15 @@ _GENERIC: Dict[str, Callable] = {
 
 
 def host_backend(op: str) -> Callable:
-    """Host (numpy) implementation of ``op``."""
+    """Host (numpy) implementation of ``op``, inside a ``jita.host.<op>``
+    span."""
     fn = _GENERIC[op]
-    return functools.partial(fn, np)
+
+    def run(*args, **kwargs):
+        with span(f"host.{op}", op=op):
+            return fn(np, *args, **kwargs)
+
+    return run
 
 
 def device_backend(op: str) -> Callable:
@@ -324,12 +331,15 @@ def device_backend(op: str) -> Callable:
     (repro.kernels holds kernels for window_agg and the kmeans
     assignment step, checked against these operators by the tests).
     Matmuls run at full float32 precision: a TPU's default is a single
-    bfloat16 pass, which would break parity with the host backend.
+    bfloat16 pass, which would break parity with the host backend. The
+    call sits in a ``jita.device.<op>`` span: the host's time issuing the
+    operator, with any copy of a host input and any wait on a result
+    inside it.
     """
     fn = _GENERIC[op]
 
     def run(*args, **kwargs):
-        with jax.default_matmul_precision("highest"):
+        with jax.default_matmul_precision("highest"), span(f"device.{op}", op=op):
             return fn(jnp, *args, **kwargs)
 
     return run
