@@ -274,8 +274,7 @@ def _join(xp, *parts):
     out = []
     for p in parts:
         if p.shape[0] != n:  # tile summaries up to the longest table
-            reps = -(-n // p.shape[0])
-            p = xp.concatenate([p] * reps, axis=0)[:n]
+            p = xp.take(p, xp.arange(n) % p.shape[0], axis=0)
         out.append(p)
     return xp.concatenate(out, axis=1)
 

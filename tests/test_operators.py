@@ -1,5 +1,7 @@
 """DS operators: host/device parity + window properties."""
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -64,6 +66,40 @@ def test_window_agg_matches_bruteforce():
     for t in range(40):
         lo = max(t - 4, 0)
         np.testing.assert_allclose(out[t], v[lo:t + 1].mean(0), rtol=1e-5)
+
+
+def _join_by_copies(xp, s, table, col):
+    """The join of a summary, a table and a column, with the summary
+    broadcast as a list of whole copies: the oracle."""
+    s = s[:, None] if s.ndim == 1 else s
+    n = table.shape[0]
+    reps = -(-n // s.shape[0])
+    return xp.concatenate([xp.concatenate([s] * reps, axis=0)[:n], table,
+                           col[:, None]], axis=1)
+
+
+@pytest.mark.parametrize("summary", [(4,), (4, 3), (5,), (5, 4), "full"],
+                         ids=["4", "4x3", "5", "5x4", "full"])
+@pytest.mark.parametrize("n", [8, 4096, 4099])
+@pytest.mark.parametrize("xp", [np, jnp], ids=["numpy", "jax"])
+def test_join_broadcast_matches_copies(xp, n, summary):
+    rng = np.random.default_rng(n)
+    shape = (n, 2) if summary == "full" else summary
+    s = rng.normal(0, 1, shape).astype(np.float32)
+    table = rng.normal(0, 1, (n, 4)).astype(np.float32)
+    col = rng.normal(0, 1, n).astype(np.float32)
+    got = ops._join(xp, s, table, col)
+    want = _join_by_copies(xp, s, table, col)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_join_program_size_independent_of_rows():
+    """The device join's program does not grow with the rows it fills."""
+    def eqns(n):
+        args = (jnp.zeros((5, 4), jnp.float32), jnp.zeros((n, 4), jnp.float32),
+                jnp.zeros((n,), jnp.float32))
+        return len(jax.make_jaxpr(lambda *a: ops._join(jnp, *a))(*args).eqns)
+    assert eqns(4096) == eqns(65536)
 
 
 # -- windows ---------------------------------------------------------------------
