@@ -90,14 +90,21 @@ def _flag_flips(got, ref, margin, tie: float) -> int:
     return int(np.sum(diff & (np.abs(margin) > tie)))
 
 
-def compare(got: Dict[str, Any], ref: Dict[str, Any], tie: float
-            ) -> Tuple[Dict[str, float], Dict[str, int]]:
+def compare(got: Dict[str, Any], ref: Dict[str, Any], tie: float,
+            settled: float) -> Tuple[Dict[str, float], Dict[str, int]]:
     """Per output of one instance: the relative error of each continuous
     output, and the discrete decisions (assignments, flags, the sweep's k)
     that differ from the reference's beyond a near-tie. Each continuous
     output is scaled by the magnitude of the quantity it is computed from,
     so that values that cancel to about 0 (the intercept, the correlation of
-    two principal components) are judged in the units of their inputs."""
+    two principal components) are judged in the units of their inputs.
+
+    The sweep's centroids are judged in units of the reference's own last
+    Lloyd pass: where that pass moved them by more than ``settled``
+    (relative to its input's magnitude), their error is divided by
+    ``step / settled``. Where the iteration is still in mid-move after its
+    last pass, a difference in the last digit of its input moves the result
+    by that much more, in the program and in the reference alike."""
     err: Dict[str, float] = {}
     bad: Dict[str, int] = {}
     x_scale = _amax(ref["clean_missing"])
@@ -124,7 +131,8 @@ def compare(got: Dict[str, Any], ref: Dict[str, Any], tie: float
     if int(gk) != int(k):
         bad["sweep_clustering.k"] = 1
     else:
-        err["sweep_clustering.centroids"] = _err(gc, c, _amax(p_ref))
+        err["sweep_clustering.centroids"] = (
+            _err(gc, c, _amax(p_ref)) / max(1.0, ref["sweep_step"] / settled))
         bad["sweep_clustering"] = _assign_flips(p_ref, c, gc, ga, a, tie)
     y_std = float(np.std(np.asarray(p_ref, np.float64)[:, 0]))
     w, b = ref["linreg"]["model"]
@@ -152,7 +160,8 @@ def compare(got: Dict[str, Any], ref: Dict[str, Any], tie: float
 def reference_outputs(ref_mod, raw: np.ndarray, ops: Dict[str, Any]
                       ) -> Dict[str, Any]:
     """The float64 reference of one raw batch, plus the anomaly flags'
-    relative margins for the near-tie rule.
+    relative margins for the near-tie rule and the size of the sweep's last
+    Lloyd pass (``sweep_step``, see :func:`compare`).
 
     A flag compares ``|x - mean|`` with ``z`` standard deviations of a short
     window. The deviation comes from ``E[x^2] - mean^2``, so rounding moves
@@ -169,6 +178,11 @@ def reference_outputs(ref_mod, raw: np.ndarray, ops: Dict[str, Any]
     sd = np.sqrt(np.maximum(ex2 - mu * mu, 1e-12))
     scale = np.abs(wa) + np.abs(mu) + an["z"] * ex2 / (2 * sd)
     out["anomaly_margin"] = (np.abs(wa - mu) - an["z"] * sd) / scale
+    sw = ops["sweep_clustering"]
+    p = out["pca"]["x"]
+    c, _a, k = out["sweep_clustering"]["fit"]
+    before = ref_mod._kmeans(np, np.matmul, p, int(k), sw["iters"] - 1)[0]
+    out["sweep_step"] = _err(c, before, _amax(p))
     return out
 
 
@@ -352,11 +366,13 @@ def check(ctx, kept: Dict[int, Dict[str, Any]], batches: List[np.ndarray]
         b = i % len(batches)
         if b not in refs:
             refs[b] = reference_outputs(ref_mod, batches[b], conf["operators"])
-        readings.append(compare(_np(kept[i]), refs[b], lim["near_tie"]))
+        readings.append(compare(_np(kept[i]), refs[b], lim["near_tie"],
+                                lim["sweep_settled"]))
     out = summarise(readings, lim)
     for c in ctx.controls:
         ctl = [compare(control_outputs(ref_mod, batches[b], conf["operators"], c),
-                       ref, lim["near_tie"]) for b, ref in refs.items()]
+                       ref, lim["near_tie"], lim["sweep_settled"])
+               for b, ref in refs.items()]
         out.update({f"control_{c}.{k}": v for k, v in summarise(ctl, lim).items()})
     return out
 

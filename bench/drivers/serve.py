@@ -15,13 +15,13 @@ window offers the requests due in ``--seconds``; afterwards the engine runs
 on until every offered request has finished, at most ``drain_s`` longer, so
 that each due request's latency is measured.
 
-* ``ttft_p90_ms``: due time to the end of the tick that produced the
-  request's first token, 90th percentile over the requests due in the
-  window;
-* ``tpot_p90_ms``: (last token - first token) / (output tokens - 1) per
-  request, 90th percentile;
-* ``output_tokens_per_s``: tokens produced by ticks that ended inside the
-  window, over the window.
+* ``ttft_p50_ms``: due time to the end of the tick that produced the
+  request's first token, median over the requests due in the window;
+* ``tpot_p50_ms``: (last token - first token) / (output tokens - 1) per
+  request, median.
+
+At the chat cell's rate a window has 16 requests due, too few for a tail:
+both are medians.
 
 Correct: after the window, a sample of the finished requests drawn from the
 seed, with the longest among them, is run through the float32 reference
@@ -184,7 +184,6 @@ def run(ctx):
     t0 = time.perf_counter()
     ctx.tracer.start()
     nxt = 0
-    tokens_in_window = 0
     lateness: List[float] = []
     while True:
         now = time.perf_counter() - t0
@@ -232,8 +231,6 @@ def run(ctx):
             served[admit.rid].first = t_tick
         for r in eng.finished[n_before:]:
             served[r.rid].last = t_tick
-        if t_tick <= ctx.seconds:
-            tokens_in_window += len(kv) + (admit is not None)
         ctx.tracer.poll()
         if t_tick > ctx.seconds + drain_s:
             break
@@ -247,12 +244,12 @@ def run(ctx):
     notes = [f"requests due {len(reqs)}, finished {len(done)}, shed "
              f"{len(dropped)}, generator lateness p50 "
              f"{float(np.percentile(lateness, 50)) if lateness else 0.0!r} s "
-             f"max {max(lateness, default=0.0)!r} s, ttft p50 "
-             f"{float(np.percentile(ttft, 50)) if ttft else 0.0!r} ms, tpot p50 "
-             f"{float(np.percentile(tpot, 50)) if tpot else 0.0!r} ms"]
-    e2e = {"ttft_p90_ms": float(np.percentile(ttft, 90)) if ttft else float("inf"),
-           "tpot_p90_ms": float(np.percentile(tpot, 90)) if tpot else float("inf"),
-           "output_tokens_per_s": tokens_in_window / ctx.seconds}
+             f"max {max(lateness, default=0.0)!r} s, ttft p90 "
+             f"{float(np.percentile(ttft, 90)) if ttft else 0.0!r} ms, tpot p90 "
+             f"{float(np.percentile(tpot, 90)) if tpot else 0.0!r} ms"]
+    inf = float("inf")
+    e2e = {"ttft_p50_ms": float(np.percentile(ttft, 50)) if ttft else inf,
+           "tpot_p50_ms": float(np.percentile(tpot, 50)) if tpot else inf}
 
     # free the engine's caches before the reference takes the chip
     del eng, gw
@@ -305,25 +302,20 @@ def served_gaps(ref, w, m_items, max_seq: int, prompt: np.ndarray,
 
 
 def check(ctx, p, done: List[Served], prompts) -> Dict[str, List[float]]:
+    """The widest served gap over the sampled requests' tokens, and the same
+    of each control's first choices."""
     conf = ctx.cell.config
     lim = conf["checks"]
     ref = ctx.reference
     w = reference_weights(p["params"])
     m_items = tuple((k, conf["model"][k]) for k in REFERENCE_KEYS)
-    worst, n_tok = float("-inf"), 0
-    for s in sample(done, ctx.seed, lim["sample"]):
-        out = np.asarray(s.spec.output, np.int64)
-        g = served_gaps(ref, w, m_items, conf["engine"]["max_seq"],
-                        prompts[s.req.rid], out)
-        worst = max(worst, float(g.max()))
-        n_tok += len(g)
-    if n_tok == 0:
-        worst = float("inf")
-    out = {"served_gap": [worst, lim["served_gap"]]}
-    for q in ctx.controls:
+    picked = sample(done, ctx.seed, lim["sample"])
+    out: Dict[str, List[float]] = {}
+    for q in (None,) + tuple(ctx.controls):
         gaps = [served_gaps(ref, w, m_items, conf["engine"]["max_seq"],
-                            prompts[s.req.rid], np.asarray(s.spec.output),
-                            quant=q).max()
-                for s in sample(done, ctx.seed, lim["sample"])]
-        out[f"control_{q}.served_gap"] = [float(max(gaps)), lim["served_gap"]]
+                            prompts[s.req.rid],
+                            np.asarray(s.spec.output, np.int64), quant=q).max()
+                for s in picked]
+        name = f"control_{q}.served_gap" if q else "served_gap"
+        out[name] = [float(max(gaps, default=np.inf)), lim["served_gap"]]
     return out

@@ -84,23 +84,9 @@ def _applies(metric: Dict[str, Any], cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
-def load_manifest(root: pathlib.Path = ROOT,
-                  bench: pathlib.Path = BENCH) -> Dict[str, Any]:
-    """``BENCHMARK.json``, with the entries of ``bench/queued.json``
-    appended: cells written but not yet proven on the chip, which the
-    benchmark's own runs never name. Calibration runs and tests drive them
-    by name until a later benchmark change moves them into the manifest."""
-    manifest = load_json(root / "BENCHMARK.json")
-    queued = bench / "queued.json"
-    if queued.exists():
-        for key, entries in load_json(queued).items():
-            manifest[key] = manifest[key] + entries
-    return manifest
-
-
 def load_cell(name: str, root: pathlib.Path = ROOT,
               bench: pathlib.Path = BENCH) -> Cell:
-    manifest = load_manifest(root, bench)
+    manifest = load_json(root / "BENCHMARK.json")
     cells = {w["name"]: w for w in manifest["workloads"]}
     if name not in cells:
         raise KeyError(f"no workload {name!r}; one of {sorted(cells)}")

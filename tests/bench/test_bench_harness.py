@@ -107,3 +107,48 @@ def test_unknown_workload_and_device(checkout):
     with pytest.raises(KeyError):
         run.peaks_for("TPU v9 imaginary")
     assert run.peaks_for("TPU v5 lite")["flops_per_s"] == 197e12
+
+
+CHAT = "stablelm-1.6b.chat"
+SERVE_READERS = {"gateway.ms_per_request", "engine.prefill_ms",
+                 "engine.decode_step_ms", "model.decode_step_roofline",
+                 "model.decode_mfu", "device.idle_share.serve"}
+
+
+def _manifest() -> dict:
+    return json.loads((REPO / "BENCHMARK.json").read_text())
+
+
+def test_chat_cell_from_the_manifest_alone():
+    """The serving cell is the manifest's own, with no file of queued
+    entries beside it, and carries its end-to-end metrics and six
+    readers."""
+    assert not (REPO / "bench" / "queued.json").exists()
+    assert CHAT in {w["name"] for w in _manifest()["workloads"]}
+    cell = run.load_cell(CHAT)
+    assert cell.chips == 1 and cell.config["kind"] == "serve"
+    assert cell.config["name"] == "stablelm-1.6b"
+    assert {m["name"] for m in cell.end_to_end} == {
+        "ttft_p50_ms", "tpot_p50_ms", "setup_s"}
+    assert {m["name"] for m in cell.per_layer} == SERVE_READERS
+    for name in SERVE_READERS:
+        assert (REPO / "bench" / "metrics" / f"{name}.py").is_file()
+
+
+@pytest.mark.parametrize("section", ["end_to_end", "per_layer"])
+def test_metric_workloads_name_cells_of_the_manifest(section):
+    cells = {w["name"] for w in _manifest()["workloads"]}
+    for m in _manifest()[section]:
+        assert set(m.get("workloads", [])) <= cells, m["name"]
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in
+                                  json.loads((REPO / "BENCHMARK.json")
+                                             .read_text())["workloads"]])
+def test_every_cell_reports_setup_another_metric_and_a_layer(cell):
+    c = run.load_cell(cell)
+    names = {m["name"] for m in c.end_to_end}
+    assert "setup_s" in names and len(names) >= 2
+    assert c.per_layer
+    moved = {m["moves"] for m in c.per_layer}
+    assert moved <= names
