@@ -28,12 +28,29 @@ seed, with the longest among them, is run through the float32 reference
 (``bench/reference/<config>.py``) over prompt + served tokens; the widest
 gap by which a served token's reference logit lies below the reference's
 best at that position is compared with the configuration's limit.
+
+The reference owns its layout. Each reference of kind ``serve`` exports
+
+* ``MODEL_KEYS``: the settings of the configuration's ``model`` section
+  that its ``logits`` reads (handed to it as a tuple of items);
+* ``from_program(params, model)``: the harness's weights, made in the
+  program's parameter layout, rearranged into the reference's, as the same
+  arrays, uncast;
+* ``logits(w, tokens, m, quant=None)``: float32 logits of one padded
+  sequence.
+
+So the reference receives the program's arrays in their served type
+(bfloat16) and casts them to float32 one block at a time, as StableLM's
+does per layer under its scan: a configuration whose weights fill the chip
+is checked with no more than one block in float32 beside them (for sparse
+experts the block is an expert).
 """
 
 from __future__ import annotations
 
 import collections
 import gc
+import math
 import time
 import traceback
 from typing import Any, Dict, List, Optional
@@ -44,9 +61,6 @@ import gen
 
 #: request ids of the warm-up requests (above any window's)
 WARM_RID = 10**9
-#: the model settings the reference reads
-REFERENCE_KEYS = ("n_heads", "head_dim", "norm_eps", "rotary_pct",
-                  "rope_theta")
 
 
 # ---------------------------------------------------------------------------
@@ -56,9 +70,20 @@ REFERENCE_KEYS = ("n_heads", "head_dim", "norm_eps", "rotary_pct",
 
 def make_weights(model_mod, cfg, init: Dict[str, float], seed: int):
     """Random weights in the program's parameter layout (taken from the
-    shapes of its ``init``), in the configuration's dtype: matrices and
-    embeddings ``N(0, std)``, q/k/v biases ``N(0, bias_std)``, norm scales
-    1, every other bias 0. One jitted call on the device."""
+    shapes of its ``init``), in the configuration's dtype, by leaf name:
+
+    * norm scales 1; q/k/v biases ``N(0, bias_std)``; every other bias 0;
+    * a Mamba mixer's per-channel leaves as Mamba publishes them (Gu & Dao,
+      S4D-real), so that its state decays over many tokens: ``A_log`` =
+      log(1..d_state) along its last axis, ``D`` 1, ``conv_b`` 0, and
+      ``dt_bias`` the inverse softplus of a step drawn log-uniform in
+      [1e-3, 1e-1] from the leaf's own key;
+    * every other leaf (matrices, embeddings, routers) ``N(0, std)``.
+
+    A rule fills a leaf whole, so it holds in each layer of a leaf stacked
+    over the scanned repeats. Every leaf draws from its own key of one
+    split, so a tree without a given kind of leaf is made as before. One
+    jitted call on the device."""
     import jax
     import jax.numpy as jnp
 
@@ -69,11 +94,20 @@ def make_weights(model_mod, cfg, init: Dict[str, float], seed: int):
 
     def leaf(path, sd, key):
         name = getattr(path[-1], "key", None)
-        if name == "scale":
+        if name in ("scale", "D"):
             return jnp.ones(sd.shape, dt)
+        if name == "A_log":
+            n = sd.shape[-1]
+            a = jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32))
+            return jnp.broadcast_to(a, sd.shape).astype(dt)
+        if name == "dt_bias":
+            lo, hi = math.log(1e-3), math.log(1e-1)
+            step = jnp.exp(jax.random.uniform(key, sd.shape, jnp.float32)
+                           * (hi - lo) + lo)
+            return (step + jnp.log(-jnp.expm1(-step))).astype(dt)
         if name in ("bq", "bk", "bv"):
             std = init["bias_std"]
-        elif name in ("bias", "bo"):
+        elif name in ("bias", "bo", "conv_b"):
             return jnp.zeros(sd.shape, dt)
         else:
             std = init["std"]
@@ -86,25 +120,6 @@ def make_weights(model_mod, cfg, init: Dict[str, float], seed: int):
 
     key = jax.random.PRNGKey(int(gen.rng_for(seed, 6).integers(2**31)))
     return jax.jit(make)(key)
-
-
-def reference_weights(params) -> Dict[str, Any]:
-    """The harness's weights in the reference's layout (a dense model whose
-    layers are one scanned period)."""
-    (blk,) = params["scan"]
-    a, f = blk["attn"], blk["mlp"]
-    return {
-        "embed": params["embed"]["embedding"],
-        "lm_head": params["embed"]["lm_head"],
-        "final_ln": (params["final_norm"]["scale"], params["final_norm"]["bias"]),
-        "layers": {
-            "ln1_s": blk["norm1"]["scale"], "ln1_b": blk["norm1"]["bias"],
-            "wq": a["wq"], "bq": a["bq"], "wk": a["wk"], "bk": a["bk"],
-            "wv": a["wv"], "bv": a["bv"], "wo": a["wo"],
-            "ln2_s": blk["norm2"]["scale"], "ln2_b": blk["norm2"]["bias"],
-            "w_gate": f["wg"], "w_up": f["wi"], "w_down": f["wo"],
-        },
-    }
 
 
 # ---------------------------------------------------------------------------
@@ -307,8 +322,8 @@ def check(ctx, p, done: List[Served], prompts) -> Dict[str, List[float]]:
     conf = ctx.cell.config
     lim = conf["checks"]
     ref = ctx.reference
-    w = reference_weights(p["params"])
-    m_items = tuple((k, conf["model"][k]) for k in REFERENCE_KEYS)
+    w = ref.from_program(p["params"], conf["model"])
+    m_items = tuple((k, conf["model"][k]) for k in ref.MODEL_KEYS)
     picked = sample(done, ctx.seed, lim["sample"])
     out: Dict[str, List[float]] = {}
     for q in (None,) + tuple(ctx.controls):

@@ -16,6 +16,9 @@ first rounded to that type with one scale per output channel: the control.
 Weights come as a dict: ``embed`` (V, d), ``lm_head`` (d, V), ``final_ln``
 (scale, bias) and ``layers``, a dict of arrays stacked over layers:
 ``ln1_s ln1_b wq bq wk bk wv bv wo ln2_s ln2_b w_gate w_up w_down``.
+``from_program`` puts the harness's weights, made in the program's
+parameter layout, into this one; ``MODEL_KEYS`` names the settings of the
+configuration's ``model`` section that ``logits`` reads.
 """
 
 from __future__ import annotations
@@ -28,6 +31,27 @@ import jax.numpy as jnp
 
 HI = jax.lax.Precision.HIGHEST
 MATRICES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+#: the model settings ``logits`` reads
+MODEL_KEYS = ("n_heads", "head_dim", "norm_eps", "rotary_pct", "rope_theta")
+
+
+def from_program(params, model) -> Dict[str, Any]:
+    """The harness's weights in the reference's layout (a dense model whose
+    layers are one scanned period), uncast."""
+    (blk,) = params["scan"]
+    a, f = blk["attn"], blk["mlp"]
+    return {
+        "embed": params["embed"]["embedding"],
+        "lm_head": params["embed"]["lm_head"],
+        "final_ln": (params["final_norm"]["scale"], params["final_norm"]["bias"]),
+        "layers": {
+            "ln1_s": blk["norm1"]["scale"], "ln1_b": blk["norm1"]["bias"],
+            "wq": a["wq"], "bq": a["bq"], "wk": a["wk"], "bk": a["bk"],
+            "wv": a["wv"], "bv": a["bv"], "wo": a["wo"],
+            "ln2_s": blk["norm2"]["scale"], "ln2_b": blk["norm2"]["bias"],
+            "w_gate": f["wg"], "w_up": f["wi"], "w_down": f["wo"],
+        },
+    }
 
 
 def _mm(a, b):

@@ -8,6 +8,10 @@ Of it this module keeps:
   as ``(name, start_ns, end_ns)``;
 * on the host, the harness's own annotations (``bench.<span>``, see
   :mod:`spans`), with their arguments;
+* apart from them, the program's own spans (``jita.<span>``, the program's
+  ``core/spans.py``), with their arguments percent-decoded as that module
+  encodes them: a reader takes them through :meth:`Trace.program`, and
+  nothing else here reads them;
 * the traced window: the ``bench.traced`` annotation.
 
 Every number is derived from these lists by the functions below, so a
@@ -19,11 +23,13 @@ from __future__ import annotations
 import dataclasses
 import glob
 import os
+import urllib.parse
 from typing import Dict, List, Optional, Sequence, Tuple
 
 Interval = Tuple[str, float, float]
 
 PREFIX = "bench."
+PROGRAM_PREFIX = "jita."
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 
@@ -66,6 +72,8 @@ class Trace:
     spans: List[HostSpan]
     window: Tuple[float, float]
     _merged: Optional[Dict[str, List[Tuple[float, float]]]] = None
+    #: the program's spans, named without their prefix
+    program_spans: List[HostSpan] = dataclasses.field(default_factory=list)
 
     # -- device busy time ---------------------------------------------------
     def merged(self) -> Dict[str, List[Tuple[float, float]]]:
@@ -97,6 +105,12 @@ class Trace:
     def named(self, name: str) -> List[HostSpan]:
         lo, hi = self.window
         return [s for s in self.spans
+                if s.name == name and s.t0 >= lo and s.t1 <= hi]
+
+    def program(self, name: str) -> List[HostSpan]:
+        """The program's spans ``jita.<name>`` inside the window."""
+        lo, hi = self.window
+        return [s for s in self.program_spans
                 if s.name == name and s.t0 >= lo and s.t1 <= hi]
 
     def busy_in(self, spans: Sequence[HostSpan]) -> float:
@@ -166,11 +180,17 @@ def _stat(v: object) -> object:
     return v.decode() if isinstance(v, bytes) else v
 
 
+def _program_stat(v: object) -> object:
+    v = _stat(v)
+    return urllib.parse.unquote(v) if isinstance(v, str) else v
+
+
 def from_profile(pd) -> Trace:
     """Reduce a ``jax.profiler.ProfileData``."""
     ops: Dict[str, List[Interval]] = {}
     modules: Dict[str, List[Interval]] = {}
     spans: List[HostSpan] = []
+    program: List[HostSpan] = []
     for plane in pd.planes:
         if plane.name.startswith("/device:") and "CPU" not in plane.name:
             for line in plane.lines:
@@ -187,6 +207,11 @@ def from_profile(pd) -> Trace:
                             e.name[len(PREFIX):], float(e.start_ns),
                             float(e.start_ns + e.duration_ns),
                             {k: _stat(v) for k, v in e.stats}))
+                    elif e.name.startswith(PROGRAM_PREFIX):
+                        program.append(HostSpan(
+                            e.name[len(PROGRAM_PREFIX):], float(e.start_ns),
+                            float(e.start_ns + e.duration_ns),
+                            {k: _program_stat(v) for k, v in e.stats}))
     for p in list(modules):
         ops.setdefault(p, modules[p])
     traced = [s for s in spans if s.name == "traced"]
@@ -200,7 +225,9 @@ def from_profile(pd) -> Trace:
                          for _n, s, e in evs):
             window = (t0, t1)
     spans.sort(key=lambda s: s.t0)
-    return Trace(ops=ops, modules=modules, spans=spans, window=window)
+    program.sort(key=lambda s: s.t0)
+    return Trace(ops=ops, modules=modules, spans=spans, window=window,
+                 program_spans=program)
 
 
 def read_dir(directory: os.PathLike) -> Trace:

@@ -71,6 +71,81 @@ def test_cell_from_new_files_only(checkout):
     assert {k: v for k, v in after.items() if k in before} == before
 
 
+#: the reference of a dense decoder whose scanned period holds two layers:
+#: StableLM-2's, over the layers in their order, repeat by repeat
+PAIR_REFERENCE = '''"""Plain float32 reference of a dense decoder of two-layer periods."""
+
+import importlib.util
+import pathlib
+
+import jax.numpy as jnp
+
+_spec = importlib.util.spec_from_file_location(
+    "stablelm_reference", pathlib.Path(__file__).with_name("stablelm-1.6b.py"))
+base = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(base)
+MODEL_KEYS = base.MODEL_KEYS
+logits = base.logits
+
+
+def from_program(params, model):
+    """Each scanned position's layers in StableLM's layout, interleaved:
+    layer ``r * period + j`` is repeat ``r`` of position ``j``."""
+    one = [base.from_program(dict(params, scan=[blk]), model)
+           for blk in params["scan"]]
+    layers = {k: jnp.stack([o["layers"][k] for o in one], axis=1)
+              .reshape((-1,) + one[0]["layers"][k].shape[1:])
+              for k in one[0]["layers"]}
+    return dict(one[0], layers=layers)
+'''
+
+
+def test_serve_cell_from_new_files_only(checkout, monkeypatch):
+    """A serve configuration of another layout (two scanned positions, which
+    StableLM's reference cannot unpack) enters with a configuration, its
+    reference and manifest entries, and nothing else changed; the same cell
+    with its decode step's cache left unchanged is not correct."""
+    from repro.serve import engine
+    from test_bench_faults_serve import _unchanged
+
+    bench = checkout / "bench"
+    before = _digest(bench)
+    conf = json.loads((bench / "configs" / "stablelm-1.6b.json").read_text())
+    conf["name"] = "tiny-pair"
+    conf["model"].update(name="tiny-pair", n_layers=4,
+                         layer_pattern=["attn", "attn"])
+    write_json(bench / "configs" / "tiny-pair.json", conf)
+    (bench / "reference" / "tiny-pair.py").write_text(PAIR_REFERENCE)
+    manifest = json.loads((checkout / "BENCHMARK.json").read_text())
+    manifest["configs"].append({
+        "name": "tiny-pair",
+        "source": "https://huggingface.co/stabilityai/stablelm-2-1_6b",
+        "file": "bench/configs/tiny-pair.json", "reduced": ["n_layers"],
+        "why": "a dense decoder of two-layer periods"})
+    manifest["workloads"].append({
+        "name": "tiny-pair.chat", "config": "tiny-pair", "traffic": "chat",
+        "chips": 1, "why": "the chat mix on a two-position period"})
+    for m in manifest["end_to_end"]:
+        if CHAT in m.get("workloads", []):
+            m["workloads"].append("tiny-pair.chat")
+    write_json(checkout / "BENCHMARK.json", manifest)
+
+    def execute():
+        return run.execute("tiny-pair.chat", 2**31 + 29, 2.0, False,
+                           root=checkout, bench=bench, platform="cpu")
+
+    res = execute()
+    assert res["correct"] is True
+    assert set(res["metrics"]) == {"ttft_p50_ms", "tpot_p50_ms", "setup_s"}
+    after = _digest(bench)
+    assert {k: v for k, v in after.items() if k in before} == before
+    monkeypatch.setattr(engine, "build_decode_step", _unchanged)
+    res = execute()
+    assert res["correct"] is False
+    gap = res["checks"]["served_gap"]
+    assert gap["value"] > gap["limit"]
+
+
 def _no_stdout_result(proc) -> bool:
     lines = proc.stdout.strip().splitlines()
     return not lines or not lines[-1].startswith("{")

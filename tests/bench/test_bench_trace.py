@@ -58,10 +58,16 @@ def test_breakdown():
                                   "gateway": 30e-9})
 
 
-def test_from_profile_text_proto():
-    """The same reduction from an XSpace, as the profiler writes it."""
+def _xspace(program: bool = False):
+    """An XSpace as the profiler writes it: one device plane, and a host
+    plane with the harness's window and a tick; with ``program`` also a
+    program span whose task name the program wrote percent-encoded."""
     from jax.profiler import ProfileData
 
+    event = """
+        events { metadata_id: 3 offset_ps: 6000 duration_ps: 30000
+          stats { metadata_id: 7 str_value: "ds_workload%230%2Cscore" }
+          stats { metadata_id: 8 int64_value: 16 } }""" if program else ""
     text = """
     planes {
       id: 1 name: "/device:TPU:0"
@@ -78,14 +84,39 @@ def test_from_profile_text_proto():
       id: 2 name: "/host:CPU"
       lines { id: 3 name: "python" timestamp_ns: 1000
         events { metadata_id: 1 offset_ps: 0 duration_ps: 100000 }
-        events { metadata_id: 2 offset_ps: 5000 duration_ps: 40000 } }
+        events { metadata_id: 2 offset_ps: 5000 duration_ps: 40000 }%s }
       event_metadata { key: 1 value { id: 1 name: "bench.traced" } }
       event_metadata { key: 2 value { id: 2 name: "bench.tick" } }
+      event_metadata { key: 3 value { id: 3 name: "jita.serve.x" } }
+      stat_metadata { key: 7 value { id: 7 name: "instance" } }
+      stat_metadata { key: 8 value { id: 8 name: "tasks" } }
     }
-    """
-    t = trace_lib.from_profile(ProfileData.from_text_proto(text))
+    """ % event
+    return ProfileData.from_text_proto(text)
+
+
+def test_from_profile_text_proto():
+    """The same reduction from an XSpace, as the profiler writes it."""
+    t = trace_lib.from_profile(_xspace())
     assert t.window == (1000.0, 1100.0)
     assert t.busy_ns(*t.window) == 30.0
     assert t.idle_share() == pytest.approx(0.7)
     assert t.busy_in(t.named("tick")) == 20.0
     assert len(t.module_events("jit_decode_step")) == 1
+    assert t.program("serve.x") == []
+
+
+def test_program_spans_kept_apart_and_decoded():
+    """A program span reaches a reader through ``Trace.program``, its
+    arguments decoded; what the harness's spans give stays as it was."""
+    without = trace_lib.from_profile(_xspace())
+    t = trace_lib.from_profile(_xspace(program=True))
+    (span,) = t.program("serve.x")
+    assert (span.t0, span.t1) == (1006.0, 1036.0)
+    assert span.args == {"instance": "ds_workload#0,score", "tasks": 16}
+    assert t.program("jita.serve.x") == [] and t.named("serve.x") == []
+    assert t.spans == without.spans
+    assert t.window == without.window
+    assert t.named("tick") == without.named("tick")
+    assert t.idle_gaps() == without.idle_gaps()
+    assert t.top_ops() == without.top_ops()
